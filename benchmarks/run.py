@@ -41,13 +41,17 @@ def main() -> None:
     args = ap.parse_args()
     only = args.only.split(",") if args.only else list(SUITES)
     print("name,us_per_call,derived")
+    failed = []
     for name in only:
         t0 = time.time()
         try:
             SUITES[name](fast=not args.full)
-        except Exception as e:  # keep the suite going; a failed row is data
+        except Exception as e:  # run the other suites, then exit nonzero
             print(f"{name},0,ERROR:{type(e).__name__}:{e}", file=sys.stdout)
+            failed.append(name)
         print(f"# suite {name} done in {time.time() - t0:.0f}s", flush=True)
+    if failed:
+        sys.exit(f"failed suites: {','.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -261,8 +261,10 @@ class FLTrainer:
         elif flat:
             self.state = self.program.init(key)
             # Donate the state: the (n, D) banks are updated in place across
-            # rounds instead of reallocating ~2 model copies per round.
-            self._round_jit = jax.jit(self.program.step, donate_argnums=0)
+            # rounds instead of reallocating ~2 model copies per round.  The
+            # client data rides as an argument, not an executable constant.
+            step = jax.jit(self.program.step, donate_argnums=0)
+            self._round_jit = lambda s: step(s, self.program.data)
         else:
             pkey, skey = jax.random.split(key)
             params0 = init_fn(pkey)

@@ -24,6 +24,7 @@ not the Python loop, as the wall-clock ceiling.  ``FLTrainer`` in
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, NamedTuple
 
 import jax
@@ -316,12 +317,16 @@ class RoundProgram:
 
     # -- one communication round --------------------------------------------
 
-    def step(self, state: FLState):
+    def step(self, state: FLState, data=None):
+        """One round.  ``data`` (default: the program's client data) lets a
+        jitted driver pass the client data as an argument, so that it is
+        not baked into the executable as a constant."""
+        data = self.data if data is None else data
         lr = self.lr * self.lr_decay ** state.round.astype(jnp.float32)
         keys = jax.random.split(state.key, 2 + self.n)
         key, tkey, ckeys = keys[0], keys[1], keys[2:]
         if self.mixer.kind == "central":
-            return self._central_step(state, lr, key, tkey, ckeys)
+            return self._central_step(state, lr, key, tkey, ckeys, data)
 
         # Node churn resolves FIRST: this round's liveness decides who
         # trains and whose edges survive.  A node down this round neither
@@ -361,8 +366,7 @@ class RoundProgram:
         # bank's row sharding so the vmapped local phase stays shard-local.
         ckeys = self._pin(ckeys)
         X, V, losses, accs = self.solver.update(
-            self.loss_fn, self.spec, params0, state.w, ckeys,
-            self.data, lr
+            self.loss_fn, self.spec, params0, state.w, ckeys, data, lr
         )
         V = self._pin(V) if V is not None else V
         if self.churned:
@@ -424,10 +428,10 @@ class RoundProgram:
             metrics["w_mass"] = w_new.sum() + inflight
         return new_state, metrics
 
-    def _central_step(self, state: FLState, lr, key, tkey, ckeys):
+    def _central_step(self, state: FLState, lr, key, tkey, ckeys, data):
         m = max(int(self.participation * self.n), 1)
         sel = jax.random.permutation(tkey, self.n)[:m]
-        data_sel = jax.tree.map(lambda d: d[sel], self.data)
+        data_sel = jax.tree.map(lambda d: d[sel], data)
         Xrep = jnp.broadcast_to(state.params, (m,) + state.params.shape)
         ones = jnp.ones((m,), jnp.float32)
         X, _, losses, accs = self.solver.update(
@@ -577,7 +581,8 @@ class RoundProgram:
         which rounds the eval values are valid for (non-eval rounds hold
         zeros).  Compiled drivers are memoized per (rounds, eval_every,
         test_data identity, eval_batch), so repeated supersteps of the same
-        shape reuse one executable.
+        shape reuse one executable.  The client data is an argument of the
+        compiled driver, not a constant inside it.
         """
         cache_key = (
             int(rounds), int(eval_every),
@@ -597,8 +602,8 @@ class RoundProgram:
                 else None
             )
 
-            def body(s, _):
-                s, metrics = self.step(s)
+            def body(data, s, _):
+                s, metrics = self.step(s, data)
                 if eval_fn is not None:
                     # s.round is already the post-increment (1-based) count.
                     do = jnp.mod(s.round, eval_every) == 0
@@ -614,11 +619,13 @@ class RoundProgram:
                 return s, metrics
 
             fn = jax.jit(
-                lambda s: jax.lax.scan(body, s, None, length=rounds),
+                lambda s, data: jax.lax.scan(
+                    functools.partial(body, data), s, None, length=rounds
+                ),
                 donate_argnums=0,
             )
             self._superstep_cache[cache_key] = (fn, test_data)
-        return fn(state)
+        return fn(state, self.data)
 
 
 def make_program(
@@ -816,6 +823,9 @@ def make_program(
             )
 
         client_data = jax.tree.map(_row_put, client_data)
+        # The solver's fused update kernel runs per shard (shard_map).
+        solver = dataclasses.replace(
+            solver, mesh=mesh, shard_axis=shard_axis)
     if mixer.kind != "central":
         from repro.comm.plan import resolve_backend
 

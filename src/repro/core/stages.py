@@ -69,12 +69,33 @@ class SamMomentumSolver:
     """Algorithm 1 lines 4-11 for all clients at once: gradients are vmapped
     over bank rows, the momentum/descent/de-bias step is one fused kernel
     call on the whole bank.  ``rho=0`` degrades to a single gradient pass,
-    ``alpha=0`` to plain SGD (the momentum bank drops out of the carry)."""
+    ``alpha=0`` to plain SGD (the momentum bank drops out of the carry).
+
+    ``mesh`` (set by ``make_program`` for a row-sharded bank) runs the
+    fused kernel once per shard under ``shard_map``: the compiler cannot
+    partition a Mosaic kernel itself, and the update is row-local."""
 
     local_steps: int = 5
     batch_size: int = 32
     rho: float = 0.0
     alpha: float = 0.0
+    mesh: Any = None
+    shard_axis: str = "clients"
+
+    def _fused_update(self, X, V, G, alpha, lr, w):
+        if self.mesh is None:
+            return kops.fused_update_bank(X, V, G, alpha, lr, w)
+        from jax.sharding import PartitionSpec
+
+        row, rep = PartitionSpec(self.shard_axis), PartitionSpec()
+        return jax.shard_map(
+            lambda x, v, g, lr_, w_: kops.fused_update_bank(
+                x, v, g, alpha, lr_, w_),
+            mesh=self.mesh,
+            in_specs=(row, row, row, rep, row),
+            out_specs=(row, row, row),
+            check_vma=False,
+        )(X, V, G, lr, w)
 
     def _grad_one(self, loss_fn, spec):
         def grad_one(x_i, w_i, key_i, data_i):
@@ -109,7 +130,7 @@ class SamMomentumSolver:
                 X, ks = carry
                 ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
                 G = spec.ravel_grad_stacked(G_tree, X)  # one contiguous write
-                X, _, _ = kops.fused_update_bank(X, V0, G, 0.0, lr, w)
+                X, _, _ = self._fused_update(X, V0, G, 0.0, lr, w)
                 return (X, ks), (losses, accs)
 
             (X, _), (losses, accs) = jax.lax.scan(
@@ -124,7 +145,7 @@ class SamMomentumSolver:
             # Lines 9-11 fused over the whole bank.  The de-biased z output
             # feeds the next TPU iteration from VMEM; on the CPU inline
             # path it is unused here and dead-code eliminated.
-            X, V, _ = kops.fused_update_bank(X, V, G, self.alpha, lr, w)
+            X, V, _ = self._fused_update(X, V, G, self.alpha, lr, w)
             return (X, V, ks), (losses, accs)
 
         (X, V, _), (losses, accs) = jax.lax.scan(
@@ -156,7 +177,7 @@ class ProximalSolver(SamMomentumSolver):
                 ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
                 G = spec.ravel_grad_stacked(G_tree, X)
                 G = G + self.mu * (X - X0).astype(G.dtype)
-                X, _, _ = kops.fused_update_bank(X, V0, G, 0.0, lr, w)
+                X, _, _ = self._fused_update(X, V0, G, 0.0, lr, w)
                 return (X, ks), (losses, accs)
 
             (X, _), (losses, accs) = jax.lax.scan(
@@ -175,7 +196,7 @@ class ProximalSolver(SamMomentumSolver):
             ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
             G = spec.ravel_grad_stacked(G_tree, X)
             G = G + self.mu * (X - X0).astype(G.dtype)
-            X, V, _ = kops.fused_update_bank(X, V, G, self.alpha, lr, w)
+            X, V, _ = self._fused_update(X, V, G, self.alpha, lr, w)
             return (X, V, ks), (losses, accs)
 
         (X, V, _), (losses, accs) = jax.lax.scan(
@@ -619,7 +640,7 @@ SOLVERS = {
     "sgd": lambda a: SamMomentumSolver(a.local_steps, a.batch_size, 0.0, 0.0),
     # FedProx-style proximal local objective (uses a.prox_mu).
     "proximal": lambda a: ProximalSolver(
-        a.local_steps, a.batch_size, a.rho, a.alpha, a.prox_mu),
+        a.local_steps, a.batch_size, a.rho, a.alpha, mu=a.prox_mu),
 }
 
 COMPRESSORS = {

@@ -76,6 +76,7 @@ def fused_update_pallas(
             jax.ShapeDtypeStruct((d_pad,), x.dtype),
         ],
         interpret=interpret,
+        name="fused_update",
     )(scalars, pad(x, x.dtype), pad(v, jnp.float32), pad(g, x.dtype))
     return x_new[:d], v_new[:d], z_new[:d]
 
@@ -150,6 +151,7 @@ def fused_update_bank_pallas(
             jax.ShapeDtypeStruct((n_pad, d_pad), X.dtype),
         ],
         interpret=interpret,
+        name="fused_update_bank",
     )(scalars, w_inv, pad(X, X.dtype), pad(V, jnp.float32), pad(G, X.dtype))
     if aligned:
         return x_new, v_new, z_new

@@ -7,21 +7,21 @@ fixed-shape ``(n, k_max)`` neighbor lists of
 work: a row gather plus weighted accumulate per neighbor slot.
 
 Tiling mirrors ``gossip_matmul``: n (#clients) is small, D (model size) is
-huge, so the grid streams X in ``(n, block_d)`` column panels with the whole
-index/weight block resident.  The neighbor-slot loop is a static Python
-unroll (k_max is a shape), so each grid step is ``k_max`` vectorized row
-gathers — Mosaic lowers ``jnp.take`` along the sublane axis; a
-scalar-prefetch DMA variant is the natural next step for very large n.  Off
-TPU the single-block interpret fast path runs the same body as plain traced
-jnp (zero per-block slicing, fuses into the caller's jit), exactly like
-``kernels/interpret.py`` documents.
+huge, so the grid streams X in ``(n, block_d)`` column panels.  The
+neighbor lists ride in as scalar-prefetch operands (SMEM), and the Mosaic
+body (``_row_kernel``) loops over receivers, reading each sender row of
+the resident panel with a dynamic sublane slice ``x_ref[pl.ds(src, 1)]``.
+Mosaic refuses a vectorized row gather (``jnp.take`` along the sublane
+axis) inside a kernel, so the vectorized jnp body (``_kernel``) is kept for
+the executors that run as plain traced jnp.
 
-One kernel body, four executors — all sharing ``_kernel``'s slot-by-slot
-f32 accumulation order, selected by ``repro.comm.plan.resolve_backend``:
-``gossip_gather_pallas`` (Mosaic/TPU), ``gossip_gather_panels`` (CPU
-column panels), ``gossip_gather_xla`` (partitionable whole-bank form — the
-GSPMD all-gather lowering), and ``gossip_gather_halo`` (the ``shard_map``
-halo exchange shipping only each shard's plan rows).
+Two kernel bodies, four executors — all sharing the slot-by-slot f32
+accumulation order, selected by ``repro.comm.plan.resolve_backend``:
+``gossip_gather_pallas`` (``_row_kernel``; Mosaic on TPU, the Pallas
+interpreter off it), ``gossip_gather_panels`` (CPU column panels of
+``_kernel``), ``gossip_gather_xla`` (``_kernel`` over the whole bank — the
+partitionable GSPMD all-gather lowering), and ``gossip_gather_halo`` (the
+``shard_map`` halo exchange shipping only each shard's plan rows).
 """
 from __future__ import annotations
 
@@ -30,7 +30,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.shard_map import shard_map
+from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import PartitionSpec
 
 __all__ = ["gossip_gather_pallas", "gossip_gather_panels",
@@ -54,6 +54,25 @@ def _kernel(idx_ref, wgt_ref, x_ref, o_ref):
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
+def _row_kernel(idx_ref, wgt_ref, x_ref, o_ref, *, k_max):
+    # idx_ref / wgt_ref: the flattened (n * k_max,) neighbor lists in SMEM.
+    # One receiver per loop iteration: slot l reads sender row idx[i, l] of
+    # the resident (n, block_d) panel and accumulates in ``_kernel``'s
+    # slot order, in f32.
+    def receiver(i, carry):
+        base = i * k_max
+        acc = wgt_ref[base] * x_ref[pl.ds(idx_ref[base], 1), :].astype(
+            jnp.float32)
+        for l in range(1, k_max):
+            acc += wgt_ref[base + l] * x_ref[
+                pl.ds(idx_ref[base + l], 1), :
+            ].astype(jnp.float32)
+        o_ref[pl.ds(i, 1), :] = acc.astype(o_ref.dtype)
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], receiver, 0)
+
+
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def gossip_gather_pallas(
     idx: jax.Array,  # (n, k_max) int32 sender indices (receiver-side)
@@ -63,29 +82,28 @@ def gossip_gather_pallas(
     interpret: bool = False,
 ):
     n, D = X.shape
-    d_pad = max(((D + block_d - 1) // block_d) * block_d, block_d)
-    if interpret and d_pad == D == block_d:
-        # Single unpadded block: run the kernel body directly (same traced
-        # jnp, no per-block slicing, fuses into the caller's jit).
-        from repro.kernels.interpret import run_single_block
-
-        return run_single_block(_kernel, [idx, wgt, X], [X.dtype])
-    Xp = X if d_pad == D else jnp.zeros(
-        (n, d_pad), X.dtype).at[:, :D].set(X)
-
+    k_max = idx.shape[1]
+    # Mosaic slices single rows only out of 32-bit tiles (a bf16 tile packs
+    # two rows per sublane).  Narrower banks are widened for the mix; that
+    # is exact, since every row is widened to f32 before its multiply.
+    Xw = X if X.dtype.itemsize == 4 else X.astype(jnp.float32)
+    # D is not a reduction axis: the ragged last panel computes on padding
+    # that the output write discards, so X is never copied to a padded
+    # width.
     out = pl.pallas_call(
-        _kernel,
-        grid=(d_pad // block_d,),
-        in_specs=[
-            pl.BlockSpec(idx.shape, lambda j: (0, 0)),
-            pl.BlockSpec(wgt.shape, lambda j: (0, 0)),
-            pl.BlockSpec((n, block_d), lambda j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((n, block_d), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((n, d_pad), X.dtype),
+        functools.partial(_row_kernel, k_max=k_max),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(pl.cdiv(D, block_d),),
+            in_specs=[pl.BlockSpec((n, block_d), lambda j, i_, w_: (0, j))],
+            out_specs=pl.BlockSpec((n, block_d), lambda j, i_, w_: (0, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, D), Xw.dtype),
         interpret=interpret,
-    )(idx, wgt, Xp)
-    return out if d_pad == D else out[:, :D]
+        name="gossip_gather",
+    )(idx.astype(jnp.int32).reshape(-1),
+      wgt.astype(jnp.float32).reshape(-1), Xw)
+    return out.astype(X.dtype)
 
 
 def gossip_gather_xla(idx: jax.Array, wgt: jax.Array, X: jax.Array):
@@ -211,9 +229,9 @@ def gossip_gather_halo(idx: jax.Array, wgt: jax.Array, X: jax.Array, *,
             )
 
     spec = PartitionSpec(axis)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(idx, wgt, X)
 
 

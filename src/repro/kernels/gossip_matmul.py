@@ -18,8 +18,12 @@ __all__ = ["gossip_matmul_pallas"]
 
 
 def _kernel(p_ref, x_ref, o_ref):
+    # HIGHEST: Mosaic's default contraction precision may take bf16 passes
+    # for f32 operands; push-sum mixes the bank (and its weights, see
+    # ``repro.core.pushsum.gossip_weights``) in full f32.
     o_ref[...] = jnp.dot(
-        p_ref[...], x_ref[...], preferred_element_type=jnp.float32
+        p_ref[...], x_ref[...], preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     ).astype(o_ref.dtype)
 
 
@@ -57,5 +61,6 @@ def gossip_matmul_pallas(
         out_specs=pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((n_pad, d_pad), X.dtype),
         interpret=interpret,
+        name="gossip_matmul",
     )(Pp, Xp)
     return out if (n_pad, d_pad) == (n, D) else out[:n, :D]

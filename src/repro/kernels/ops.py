@@ -1,8 +1,10 @@
 """Jitted public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs as traced jnp on the host, validating the exact TPU program logic;
-on a real TPU backend the same call sites compile to Mosaic.
+Off the TPU (``on_tpu()`` false: the CPU test suite) every wrapper defaults
+to interpreted kernels — the kernel body runs as traced jnp on the host; on
+a TPU backend the same call sites compile to Mosaic.  Nothing falls back
+from Mosaic to the interpreter: ``chip_smoke.py`` checks that the compiled
+round holds each kernel as a ``tpu_custom_call``.
 """
 from __future__ import annotations
 
@@ -157,11 +159,12 @@ def gossip_matmul(P, X, **kw):
 def gossip_gather(idx, wgt, X, **kw):
     interpret = kw.pop("interpret", not on_tpu())
     if interpret and "block_d" not in kw:
-        # Off-TPU the same kernel body runs as a fori_loop of (n, panel)
-        # column blocks: composed after the local solver, the whole-bank
-        # gather makes XLA CPU materialize one fresh (n, D) temp per
-        # neighbor slot (first-touch writes dominate); panel blocking
-        # keeps every intermediate cache-resident and bitwise identical.
+        # Off-TPU the vectorized jnp body (the Mosaic body's slot order)
+        # runs as a fori_loop of (n, panel) column blocks: composed after
+        # the local solver, the whole-bank gather makes XLA CPU
+        # materialize one fresh (n, D) temp per neighbor slot (first-touch
+        # writes dominate); panel blocking keeps every intermediate
+        # cache-resident and bitwise identical.
         from repro.kernels.gossip_gather import gossip_gather_panels
 
         return gossip_gather_panels(idx, wgt, X, **kw)

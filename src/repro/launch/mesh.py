@@ -10,19 +10,13 @@ device state; the dry-run forces 512 host devices *before* calling these.
 from __future__ import annotations
 
 import jax
-
-try:  # AxisType only exists in newer jax; older versions imply Auto.
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_clients_mesh",
            "HARDWARE"]
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 # TPU v5e constants used by the roofline model.

@@ -51,12 +51,9 @@ _STATE: list = []  # stack of (mesh, fsdp: bool, head_dim_fallback: bool)
 
 @contextlib.contextmanager
 def use_mesh(mesh: Mesh, fsdp: bool = True):
-    # jax.set_mesh only exists in newer jax; Mesh-as-context-manager is the
-    # portable spelling and enters the same default device mesh.
-    set_mesh = getattr(jax, "set_mesh", None)
     _STATE.append((mesh, fsdp))
     try:
-        with (set_mesh(mesh) if set_mesh is not None else mesh):
+        with jax.set_mesh(mesh):
             yield mesh
     finally:
         _STATE.pop()

@@ -245,7 +245,6 @@ def test_resolve_backend_with_mesh():
 # ---------------------------------------------------------------------------
 
 def test_in_manual_region_detection():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _mesh1("data")
@@ -257,8 +256,8 @@ def test_in_manual_region_detection():
         return shlib.constrain(x + 1.0, ("batch", "embed"))  # must not raise
 
     with shlib.use_mesh(mesh):
-        out = shard_map(body, mesh=mesh, in_specs=P("data"),
-                        out_specs=P("data"))(jnp.ones((4, 8)))
+        out = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                            out_specs=P("data"))(jnp.ones((4, 8)))
         assert seen["inside"] is True
         # outside the region the constraint applies normally
         y = shlib.constrain(jnp.ones((4, 8)), ("batch", "embed"))
