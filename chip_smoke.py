@@ -19,9 +19,8 @@ same program unmeshed on one device, round by round from the same state, at
 ``tests/test_sharded.py``'s tolerances, with push-sum mass checked every
 round.
 
-Printed timings are host wall-clock smoke timings (compile included where
-named); they are not device metrics.  The last stdout line is one JSON
-object naming the device; it is printed only when every check passed.
+The last stdout line is one JSON object naming the device; it is printed
+only when every check passed.
 Without a TPU the script exits nonzero before any phase.
 """
 from __future__ import annotations
@@ -31,7 +30,6 @@ import json
 import os
 import re
 import sys
-import time
 import traceback
 
 ROOT = os.path.dirname(os.path.abspath(__file__))
@@ -96,13 +94,11 @@ def train_phase(label, *, dataset, model, n, k_out, n_train, pad_to,
     from repro.core import FLTrainer, TopologyConfig, make_algo, pushsum
     from repro.kernels import ref
 
-    t0 = time.perf_counter()
     cdata, testj = client_setting(dataset, n, n_train, 1000, pad_to, seed)
     algo = make_algo("dfedsgpsm", local_steps=5, batch_size=32, lr=lr)
     topo = TopologyConfig(kind="kout", n_clients=n, k_out=k_out)
     tr = FLTrainer(model.loss, model.init, cdata, algo, topo, seed=seed)
     prog = tr.program
-    setup_s = time.perf_counter() - t0
     say(f"phase {label}: {model.name} D={prog.spec.dim} n={n} kout "
         f"k_out={k_out} dfedsgpsm K=5 batch=32 lr={lr} "
         f"sparse_mix={prog.sparse_mix}")
@@ -110,9 +106,7 @@ def train_phase(label, *, dataset, model, n, k_out, n_train, pad_to,
           f"density rule picked sparse_mix={prog.sparse_mix}, want {sparse}")
 
     # The round as compiled for the chip holds the phase's Mosaic kernels.
-    t0 = time.perf_counter()
     hlo = jax.jit(prog.step).lower(tr.state, prog.data).compile().as_text()
-    compile_s = time.perf_counter() - t0
     found = kernels_in(hlo)
     say(f"phase {label}: Mosaic kernels in the compiled round: "
         f"{sorted(found)}")
@@ -120,13 +114,9 @@ def train_phase(label, *, dataset, model, n, k_out, n_train, pad_to,
 
     # Supersteps with in-scan eval: the first compiles, the second reuses.
     half = rounds // 2
-    t0 = time.perf_counter()
     hist = tr.fit(half, test_data=testj, eval_every=half, superstep=half)
-    fit_cold_s = time.perf_counter() - t0
-    t0 = time.perf_counter()
     hist += tr.fit(rounds - half, test_data=testj, eval_every=half,
                    superstep=rounds - half)
-    fit_warm_s = time.perf_counter() - t0
     losses = [h["loss"] for h in hist]
     evals = [h["test_loss"] for h in hist if "test_loss" in h]
     say(f"phase {label}: train loss per round {losses}; in-scan test loss "
@@ -161,10 +151,6 @@ def train_phase(label, *, dataset, model, n, k_out, n_train, pad_to,
         err = rel_err(got, jax.jit(ref.gossip_matmul_ref)(P, X))
     say(f"phase {label}: one mix vs repro.kernels.ref: max rel err {err!r}")
     check(err <= REL_TOL, f"mix rel err {err} > {REL_TOL}")
-    say(f"phase {label}: smoke timings (host clock, not device metrics): "
-        f"setup_s={setup_s:.3f} round_compile_s={compile_s:.3f} "
-        f"fit_first_{half}_rounds_s={fit_cold_s:.3f} "
-        f"fit_next_{rounds - half}_rounds_s={fit_warm_s:.3f}")
 
 
 def _finite(x) -> bool:
@@ -222,7 +208,6 @@ def phase_sharded(seed, n=512):
         ("kout", TopologyConfig(kind="kout", n_clients=n, k_out=10), "halo"),
     ]
     for name, topo, gossip in cases:
-        t0 = time.perf_counter()
         sh = make_program(model.loss, model.init, cdata, algo, topo,
                           gossip=gossip, mesh=mesh)
         ref = make_program(model.loss, model.init, cdata, algo, topo,
@@ -249,8 +234,6 @@ def phase_sharded(seed, n=512):
             check(abs(mass - n) < MASS_TOL, f"{name} round {r}: mass {mass}")
             check(perr < SHARD_TOL, f"{name} round {r}: params err {perr}")
             check(werr < SHARD_TOL, f"{name} round {r}: w err {werr}")
-        say(f"sharded {name}: smoke timing (host clock, compile included, "
-            f"not a device metric): {time.perf_counter() - t0:.3f}s")
 
 
 def main() -> int:
@@ -281,7 +264,6 @@ def main() -> int:
               else [("A", phase_a), ("B", phase_b)])
     failed = []
     for label, fn in phases:
-        t0 = time.perf_counter()
         try:
             fn(args.seed)
         except Exception:  # report every phase, then fail the run
@@ -289,8 +271,7 @@ def main() -> int:
             failed.append(label)
             say(f"phase {label}: FAIL")
             continue
-        say(f"phase {label}: PASS (smoke timing, host clock: "
-            f"{time.perf_counter() - t0:.3f}s)")
+        say(f"phase {label}: PASS")
     if failed:
         print(f"chip_smoke: failed phases {failed}", file=sys.stderr)
         return 1

@@ -244,6 +244,8 @@ class FLTrainer:
         self._exp_cycle = self.program.exp_cycle
         self.paged = paged
         self.runner = None
+        # Rounds run through fit's supersteps: the profiler's step number.
+        self._fit_rounds = 0
 
         key = jax.random.PRNGKey(seed)
         if paged:
@@ -509,35 +511,51 @@ class FLTrainer:
         done = 0
         chunk = rounds if superstep <= 0 else superstep
         cadence = eval_every if test_data is not None else 0
+        span = jax.profiler.TraceAnnotation
         while done < rounds:
             length = min(chunk, rounds - done)
-            self.state, hist = self.program.run_superstep(
-                self.state, length, cadence, test_data
-            )
-            # ONE device->host transfer per superstep boundary; indexing
-            # device arrays per round would re-introduce the per-round
-            # syncs the scanned driver exists to eliminate.
-            hist = jax.device_get(hist)
-            evals = hist.get("eval_mask")
-            for i in range(length):
-                rec = {
-                    "round": done + i,
-                    "loss": float(hist["loss"][i]),
-                    "acc": float(hist["acc"][i]),
-                }
-                # Link-scenario extras: transmitted fraction (event-
-                # triggered rounds) and the exact-mass invariant.
-                for k in ("comm_fraction", "w_mass", "w_inflight"):
-                    if k in hist:
-                        rec[k] = float(hist[k][i])
-                if evals is not None and bool(evals[i]):
-                    rec["test_loss"] = float(hist["test_loss"][i])
-                    rec["test_acc"] = float(hist["test_acc"][i])
-                history.append(rec)
-                if log:
-                    log(rec)
+            # Profiler spans on the superstep boundary: the step number is
+            # the host's count of rounds fit has run, never a device read.
+            with jax.profiler.StepTraceAnnotation(
+                    "fl.superstep", step_num=self._fit_rounds):
+                with span("fl.dispatch"):
+                    self.state, hist = self.program.run_superstep(
+                        self.state, length, cadence, test_data
+                    )
+                # ONE device->host transfer per superstep boundary;
+                # indexing device arrays per round would re-introduce the
+                # per-round syncs the scanned superstep exists to eliminate.
+                with span("fl.fetch"):
+                    hist = jax.device_get(hist)
+                with span("fl.records"):
+                    history += self._records(hist, done, length, log)
             done += length
+            self._fit_rounds += length
         return history
+
+    @staticmethod
+    def _records(hist, done, length, log):
+        """The per-round history records of one fetched superstep."""
+        records = []
+        evals = hist.get("eval_mask")
+        for i in range(length):
+            rec = {
+                "round": done + i,
+                "loss": float(hist["loss"][i]),
+                "acc": float(hist["acc"][i]),
+            }
+            # Link-scenario extras: transmitted fraction (event-triggered
+            # rounds) and the exact-mass invariant.
+            for k in ("comm_fraction", "w_mass", "w_inflight"):
+                if k in hist:
+                    rec[k] = float(hist[k][i])
+            if evals is not None and bool(evals[i]):
+                rec["test_loss"] = float(hist["test_loss"][i])
+                rec["test_acc"] = float(hist["test_acc"][i])
+            records.append(rec)
+            if log:
+                log(rec)
+        return records
 
     def _fit_python_loop(self, rounds, test_data, eval_every, log):
         """Per-round host loop — the ``flat=False`` oracle's and the paged

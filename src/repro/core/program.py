@@ -382,23 +382,26 @@ class RoundProgram:
         # ``round_step``): the compressor shapes what leaves each client
         # over the network while the self-loop contribution P[ii]·X[i]
         # stays full precision; with identity compression and no mesh the
-        # phase is bitwise the pre-extraction inline sequence.
-        P = self.mixing_matrix(tkey, state)
-        if self.churned:
-            # Dead nodes leave the operator wholesale (in- AND out-edges,
-            # masked before sender normalization); the link model's
-            # per-edge drops then fail edges of the surviving support.
-            P = self.churn_model.mask_operator(
-                P, alive, symmetric=self.mixer.kind == "symmetric"
+        # phase is bitwise the pre-extraction inline sequence.  The graph
+        # and the phase are the round's ``mix``, named for the profiler.
+        with jax.named_scope("mix"):
+            P = self.mixing_matrix(tkey, state)
+            if self.churned:
+                # Dead nodes leave the operator wholesale (in- AND
+                # out-edges, masked before sender normalization); the link
+                # model's per-edge drops then fail edges of the surviving
+                # support.
+                P = self.churn_model.mask_operator(
+                    P, alive, symmetric=self.mixer.kind == "symmetric"
+                )
+            X, w_new, comp, link, extras = comm_phase(
+                self.compressor, self.mixer, P, X, state.w, comp0,
+                state.link,
+                linked=self.linked, link_model=self.link,
+                symmetric=self.mixer.kind == "symmetric",
+                pin=self._pin, pin_link=self._pin_link,
+                t=state.round,
             )
-        X, w_new, comp, link, extras = comm_phase(
-            self.compressor, self.mixer, P, X, state.w, comp0,
-            state.link,
-            linked=self.linked, link_model=self.link,
-            symmetric=self.mixer.kind == "symmetric",
-            pin=self._pin, pin_link=self._pin_link,
-            t=state.round,
-        )
         churn = state.churn
         if self.churned:
             churn = ChurnState(nkey, live_new, state.churn.tpl)
@@ -531,6 +534,7 @@ class RoundProgram:
         }
         mask = (jnp.arange(total) < n).reshape(n_chunks, batch)
 
+        @jax.named_scope("eval")
         def eval_fn(state: FLState):
             row = (
                 state.params

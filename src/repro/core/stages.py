@@ -115,6 +115,17 @@ class SamMomentumSolver:
 
         return grad_one
 
+    @staticmethod
+    def _grads(grad_one, spec, X, w, ks, data):
+        """One local step's gradients for every client, raveled into the
+        (n, D) bank: the ``sam_grad`` and ``grad_ravel`` phases of the
+        round, named for the profiler."""
+        with jax.named_scope("sam_grad"):
+            ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
+        with jax.named_scope("grad_ravel"):
+            G = spec.ravel_grad_stacked(G_tree, X)  # one contiguous write
+        return ks, G, losses, accs
+
     def update(self, loss_fn, spec, X, w, keys, data, lr):
         grad_one = self._grad_one(loss_fn, spec)
         V0 = jnp.zeros_like(X, jnp.float32)
@@ -128,8 +139,8 @@ class SamMomentumSolver:
 
             def step0(carry, _):
                 X, ks = carry
-                ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
-                G = spec.ravel_grad_stacked(G_tree, X)  # one contiguous write
+                ks, G, losses, accs = self._grads(grad_one, spec, X, w, ks,
+                                                  data)
                 X, _, _ = self._fused_update(X, V0, G, 0.0, lr, w)
                 return (X, ks), (losses, accs)
 
@@ -140,8 +151,7 @@ class SamMomentumSolver:
 
         def step(carry, _):
             X, V, ks = carry
-            ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
-            G = spec.ravel_grad_stacked(G_tree, X)  # one contiguous write
+            ks, G, losses, accs = self._grads(grad_one, spec, X, w, ks, data)
             # Lines 9-11 fused over the whole bank.  The de-biased z output
             # feeds the next TPU iteration from VMEM; on the CPU inline
             # path it is unused here and dead-code eliminated.
@@ -174,8 +184,8 @@ class ProximalSolver(SamMomentumSolver):
             # the kernel's zero momentum operand — one (n, D) zero bank.
             def step0(carry, _):
                 X, ks = carry
-                ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
-                G = spec.ravel_grad_stacked(G_tree, X)
+                ks, G, losses, accs = self._grads(grad_one, spec, X, w, ks,
+                                                  data)
                 G = G + self.mu * (X - X0).astype(G.dtype)
                 X, _, _ = self._fused_update(X, V0, G, 0.0, lr, w)
                 return (X, ks), (losses, accs)
@@ -193,8 +203,7 @@ class ProximalSolver(SamMomentumSolver):
 
         def step(carry, _):
             X, V, ks = carry
-            ks, G_tree, losses, accs = jax.vmap(grad_one)(X, w, ks, data)
-            G = spec.ravel_grad_stacked(G_tree, X)
+            ks, G, losses, accs = self._grads(grad_one, spec, X, w, ks, data)
             G = G + self.mu * (X - X0).astype(G.dtype)
             X, V, _ = self._fused_update(X, V, G, self.alpha, lr, w)
             return (X, V, ks), (losses, accs)
