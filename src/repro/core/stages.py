@@ -152,9 +152,9 @@ class SamMomentumSolver:
         def step(carry, _):
             X, V, ks = carry
             ks, G, losses, accs = self._grads(grad_one, spec, X, w, ks, data)
-            # Lines 9-11 fused over the whole bank.  The de-biased z output
-            # feeds the next TPU iteration from VMEM; on the CPU inline
-            # path it is unused here and dead-code eliminated.
+            # Lines 9-11 fused over the whole bank.  The next step de-biases
+            # its own rows (``spec.debias``), so the de-biased z output, XLA
+            # outside the kernel, is dead-code eliminated.
             X, V, _ = self._fused_update(X, V, G, self.alpha, lr, w)
             return (X, V, ks), (losses, accs)
 

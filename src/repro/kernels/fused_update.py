@@ -7,7 +7,8 @@
 Unfused, these are 3 elementwise passes = 5 HBM reads + 3 writes of the full
 model; fused it is 3 reads + 3 writes in a single pass — the update becomes
 strictly HBM-bandwidth-bound at its floor.  Scalars (alpha, eta, 1/w) ride in
-as a tiny (3,) operand broadcast to every grid step.
+as a tiny (3,) operand broadcast to every grid step.  The row-banked kernel
+(``fused_update_bank_pallas``) writes x' and v' alone: 3 reads + 2 writes.
 """
 from __future__ import annotations
 
@@ -16,6 +17,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["fused_update_pallas", "fused_update_bank_pallas"]
 
@@ -83,17 +85,53 @@ def fused_update_pallas(
 
 # ---------------------------------------------------------------------------
 # Row-banked variant: the whole (n_clients, D) flat parameter bank in one
-# call, with a per-client push-sum weight column.  Same fused arithmetic,
-# one grid step per (block_n, block_d) tile.
+# call.  The kernel writes only x' and v', one grid step per
+# (block_n, block_d) tile of the caller's unpadded banks; the de-biased z'
+# is plain XLA outside it, so a caller that drops z' never pays for it.
 # ---------------------------------------------------------------------------
 
-def _bank_kernel(s_ref, wi_ref, x_ref, v_ref, g_ref, xo_ref, vo_ref, zo_ref):
+# Every stream of the kernel (x, v, g in; x', v' out) is double-buffered in
+# VMEM; together they take at most this much: about 1 MB an f32 block of
+# 100 rows, so the fixed cost of a grid step is a few percent of its
+# transfer.
+_VMEM_BUDGET = 12 * 2**20
+# The v5e's whole VMEM, which the kernel reserves while it runs.  Left
+# free, XLA keeps a bank that fits there (MNIST's 80 MB) in VMEM across the
+# kernel, staged by copies outside it; with the reservation every stream of
+# the kernel is in HBM, and its device time covers all of its traffic.
+_VMEM_BYTES = 128 * 2**20
+# Narrowest column panel before the rows are split: below it the fixed
+# cost of a grid step, not HBM, sets the pace.
+_MIN_BLOCK_D = 512
+
+
+def _bank_tiles(n: int, d: int, x_dtype, g_dtype) -> tuple[int, int]:
+    """The (block_n, block_d) tile for an (n, d) bank, from its shape and
+    dtypes alone: every row in one block while a ``_MIN_BLOCK_D``-wide
+    panel of them fits the budget (a full-dimension block is legal at any
+    n), else a multiple of the sublane tile; then the widest multiple of
+    128 lanes that fits, or all of d."""
+    xs, gs = jnp.dtype(x_dtype).itemsize, jnp.dtype(g_dtype).itemsize
+    row_align = 8 * (4 // min(xs, gs, 4))  # sublane tile: 8 f32, 16 bf16
+    col_bytes = 2 * (2 * xs + gs + 2 * 4)  # x, x', g, v, v'; two buffers
+
+    def rows(b):  # VMEM pads a block's rows to the sublane tile
+        return -(-b // row_align) * row_align
+
+    block_n = n
+    if rows(n) * _MIN_BLOCK_D * col_bytes > _VMEM_BUDGET:
+        block_n = max(row_align, _VMEM_BUDGET // (_MIN_BLOCK_D * col_bytes)
+                      // row_align * row_align)
+    block_d = _VMEM_BUDGET // (rows(block_n) * col_bytes) // 128 * 128
+    return block_n, (d if block_d >= d else block_d)
+
+
+def _bank_kernel(s_ref, x_ref, v_ref, g_ref, xo_ref, vo_ref):
     alpha, eta = s_ref[0], s_ref[1]
     v_new = alpha * v_ref[...] + g_ref[...].astype(jnp.float32)
     x_new = x_ref[...].astype(jnp.float32) - eta * v_new
     vo_ref[...] = v_new
     xo_ref[...] = x_new.astype(xo_ref.dtype)
-    zo_ref[...] = (x_new * wi_ref[...]).astype(zo_ref.dtype)
 
 
 @functools.partial(
@@ -105,61 +143,50 @@ def fused_update_bank_pallas(
     alpha,
     eta,
     w: jax.Array,  # (n,) per-client push-sum weights
-    block_n: int = 8,
-    block_d: int = 512,
+    block_n: int | None = None,
+    block_d: int | None = None,
     interpret: bool = False,
 ):
+    """``(x', v', z')`` for the whole bank; tiles from :func:`_bank_tiles`
+    unless given."""
     n, d = X.shape
-    n_pad = max(((n + block_n - 1) // block_n) * block_n, block_n)
-    d_pad = max(((d + block_d - 1) // block_d) * block_d, block_d)
-    aligned = (n_pad, d_pad) == (n, d)
-
-    def pad(t, dt):
-        if aligned:
-            return t.astype(dt)
-        return jnp.zeros((n_pad, d_pad), dt).at[:n, :d].set(t.astype(dt))
-
-    # The copies around the kernel are the round's ``update_pad`` phase,
+    auto_n, auto_d = _bank_tiles(n, d, X.dtype, G.dtype)
+    block_n = auto_n if block_n is None else block_n
+    block_d = auto_d if block_d is None else block_d
+    # The XLA glue around the kernel is the round's ``update_pad`` phase,
     # named for the profiler; the kernel itself is not in it.
     with jax.named_scope("update_pad"):
         scalars = jnp.stack([jnp.float32(alpha), jnp.float32(eta)])
-        # Padded rows carry weight 1 so the de-bias never divides by zero.
-        w_inv = jnp.ones((n_pad, 1), jnp.float32).at[:n, 0].set(
-            1.0 / w.astype(jnp.float32))
-    if interpret and aligned and (block_n, block_d) == (n, d):
+        w_inv = (1.0 / w.astype(jnp.float32))[:, None]
+    if interpret and (block_n, block_d) == (n, d):
         from repro.kernels.interpret import run_single_block
 
-        return run_single_block(
+        x_new, v_new = run_single_block(
+            _bank_kernel, [scalars, X, V.astype(jnp.float32), G],
+            [X.dtype, jnp.float32])
+    else:
+        # D is not a reduction axis: the ragged last tiles compute on lanes
+        # and rows that the output writes discard, so no bank is ever
+        # copied to a padded shape.  x' and v' overwrite x and v in place
+        # (each tile is read before it is written): inside the solver's
+        # scan, XLA would otherwise copy both carries around the kernel.
+        tile = pl.BlockSpec((block_n, block_d), lambda i, j: (i, j))
+        x_new, v_new = pl.pallas_call(
             _bank_kernel,
-            [scalars, w_inv, X, V.astype(jnp.float32), G],
-            [X.dtype, jnp.float32, X.dtype])
+            grid=(pl.cdiv(n, block_n), pl.cdiv(d, block_d)),
+            in_specs=[pl.BlockSpec((2,), lambda i, j: (0,)), tile, tile,
+                      tile],
+            out_specs=[tile, tile],
+            out_shape=[
+                jax.ShapeDtypeStruct((n, d), X.dtype),
+                jax.ShapeDtypeStruct((n, d), jnp.float32),
+            ],
+            input_output_aliases={1: 0, 2: 1},
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_VMEM_BYTES),
+            interpret=interpret,
+            name="fused_update_bank",
+        )(scalars, X, V.astype(jnp.float32), G)
     with jax.named_scope("update_pad"):
-        operands = (scalars, w_inv, pad(X, X.dtype), pad(V, jnp.float32),
-                    pad(G, X.dtype))
-    x_new, v_new, z_new = pl.pallas_call(
-        _bank_kernel,
-        grid=(n_pad // block_n, d_pad // block_d),
-        in_specs=[
-            pl.BlockSpec((2,), lambda i, j: (0,)),
-            pl.BlockSpec((block_n, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-            pl.BlockSpec((block_n, block_d), lambda i, j: (i, j)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n_pad, d_pad), X.dtype),
-            jax.ShapeDtypeStruct((n_pad, d_pad), jnp.float32),
-            jax.ShapeDtypeStruct((n_pad, d_pad), X.dtype),
-        ],
-        interpret=interpret,
-        name="fused_update_bank",
-    )(*operands)
-    if aligned:
-        return x_new, v_new, z_new
-    with jax.named_scope("update_pad"):
-        return x_new[:n, :d], v_new[:n, :d], z_new[:n, :d]
+        z_new = (x_new.astype(jnp.float32) * w_inv).astype(X.dtype)
+    return x_new, v_new, z_new

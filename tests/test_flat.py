@@ -113,19 +113,27 @@ def test_fused_update_bank_matches_ref(n, d, dtype):
             rtol=tol, atol=tol)
 
 
-def test_fused_update_bank_blocked_grid_path():
-    """Force the multi-block pl.pallas_call route (padding + tiling)."""
-    n, d = 5, 300
+@pytest.mark.parametrize("n,d,block_n,block_d", [
+    (5, 300, 8, 128), (100, 1000, 8, 256)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_fused_update_bank_blocked_grid_path(n, d, block_n, block_d, dtype):
+    """Force the multi-block pl.pallas_call route over the unpadded bank:
+    neither block size divides it, so the last row and column tiles are
+    ragged."""
     ks = jax.random.split(jax.random.PRNGKey(1), 4)
-    X = jax.random.normal(ks[0], (n, d))
+    X = jax.random.normal(ks[0], (n, d), dtype)
     V = jax.random.normal(ks[1], (n, d))
-    G = jax.random.normal(ks[2], (n, d))
+    G = jax.random.normal(ks[2], (n, d), dtype)
     w = jax.random.uniform(ks[3], (n,), jnp.float32, 0.5, 2.0)
-    got = ops.fused_update_bank(X, V, G, 0.5, 0.1, w, block_n=8, block_d=128)
+    got = ops.fused_update_bank(X, V, G, 0.5, 0.1, w, block_n=block_n,
+                                block_d=block_d)
     want = ref.fused_update_bank_ref(X, V, G, 0.5, 0.1, w)
+    rtol, atol = (2e-2, 2e-2) if dtype == jnp.bfloat16 else (1e-5, 1e-6)
     for a, b in zip(got, want):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                   rtol=1e-5, atol=1e-6)
+        assert a.shape == b.shape == (n, d) and a.dtype == b.dtype
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=rtol, atol=atol)
 
 
 def test_gossip_bank_matches_pytree_gossip():

@@ -94,15 +94,35 @@ def test_gossip_matmul_compiles_for_v5e(one_chip, no_persistent_cache):
     assert "gossip_matmul" in _mosaic_kernels(compiled)
 
 
-def test_fused_update_bank_compiles_for_v5e(one_chip, no_persistent_cache):
-    n = 100
-    bank = _spec(one_chip, (n, D_CIFAR_CNN))
+@pytest.mark.parametrize("n,D,dtype", [
+    (100, D_CIFAR_CNN, jnp.float32),
+    (100, D_MNIST_2NN, jnp.float32),
+    (25, D_CIFAR_CNN, jnp.float32),  # one shard of 100 rows on four chips
+    (100, D_MNIST_2NN, jnp.bfloat16),  # bank_dtype=bf16
+])
+def test_fused_update_bank_compiles_for_v5e(one_chip, no_persistent_cache,
+                                            n, D, dtype):
+    """The kernel runs on the caller's unpadded banks, in tiles that fit
+    the chip's scoped VMEM: no padded copy of a bank, two (n, D) outputs."""
+    bank = _spec(one_chip, (n, D), dtype)
     scalar = _spec(one_chip, ())
     compiled = fused_update_bank_pallas.lower(
-        bank, bank, bank, scalar, scalar, _spec(one_chip, (n,)),
-        interpret=False,
+        bank, _spec(one_chip, (n, D)), bank, scalar, scalar,
+        _spec(one_chip, (n,)), interpret=False,
     ).compile()
+    text = compiled.as_text()
     assert "fused_update_bank" in _mosaic_kernels(compiled)
+    # Every bank-sized buffer is the bank itself: no (104, 1756672) of a
+    # padded copy, no pad of anything wider than the two update scalars.
+    shapes = set(re.findall(r"\b(?:f32|bf16)\[(\d+),(\d+)\]", text))
+    assert {(int(a), int(b)) for a, b in shapes} <= {(n, D), (n, 1)}
+    for rank in re.findall(r"= \w+\[([\d,]*)\]\S* pad\(", text):
+        assert rank.count(",") == 0, f"pad of a [{rank}] array"
+    (outs,) = re.findall(
+        r"%fused_update_bank(?:\.\d+)? = \((.*?)\) custom-call\(", text)
+    hlo_dtype = {"float32": "f32", "bfloat16": "bf16"}[jnp.dtype(dtype).name]
+    assert re.findall(r"(\w+)\[(\d+),(\d+)\]", outs) == [
+        (hlo_dtype, str(n), str(D)), ("f32", str(n), str(D))]
 
 
 @pytest.mark.parametrize("k_max", [1, 11])
