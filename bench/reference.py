@@ -14,9 +14,13 @@ P_ij w_j``.  After the last round followed, the consensus model (the mean
 of the client rows) is evaluated on the test set.
 
 Written from the algorithm in plain ``jax.numpy`` over parameter pytrees;
-it imports nothing of the program and takes none of its arrays.  Clients
-are processed in blocks (``lax.map``) so that the reference fits beside
-the data.  ``dtype`` and ``precision`` select the reference (float32 at
+it imports nothing of the program and takes none of its arrays.  Its device
+memory is one (n, D) bank, donated from round to round, a second for the
+mix, and one block of clients' activations: the local phase runs the
+clients in blocks (``client_block``) and writes each block's rows back into
+the bank in place; a round returns the momentum's per-leaf sums of squares,
+not the momentum; the change is read against the initial weights as one
+row.  ``dtype`` and ``precision`` select the reference (float32 at
 ``HIGHEST``) or its control (bfloat16); ``fault`` plants one of the
 faults the benchmark's check must catch (``"half_batch"``: the loss is
 the mean over the first half of each minibatch).
@@ -53,18 +57,22 @@ def leaf_norms(tree):
                       for x in jax.tree.leaves(tree)])
 
 
-def _block(n: int, want: int) -> int:
-    return max(b for b in range(1, min(n, want) + 1) if n % b == 0)
-
-
 BLOCK = 25  # the most clients the reference runs side by side
+BLOCK_BYTES = 256 * 2**20  # the most bytes of bank rows in one block
+
+
+def client_block(n: int, row_bytes: int) -> int:
+    """Clients in one block of the local phase: the largest divisor of
+    ``n`` that is at most ``BLOCK`` and whose rows of the bank take at most
+    ``BLOCK_BYTES`` (one client where a single row is larger)."""
+    return max(b for b in range(1, min(n, BLOCK) + 1)
+               if n % b == 0 and (b == 1 or b * row_bytes <= BLOCK_BYTES))
 
 
 def make_round(apply, alg, n, k_out, *, dtype, precision, fault=None):
     K, B = alg["local_steps"], alg["batch_size"]
     rho, alpha = alg["rho"], alg["momentum"]
     lr0, decay = alg["lr"], alg["lr_decay"]
-    nb = _block(n, BLOCK)
 
     def loss_fn(params, batch):
         x, y = batch["x"], batch["y"]
@@ -104,33 +112,43 @@ def make_round(apply, alg, n, k_out, *, dtype, precision, fault=None):
         flat = a.reshape(n, -1).astype(dtype)
         return jnp.dot(P, flat, precision=precision).reshape(a.shape)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=0)
     def round_fn(X, w, key, t, data):
         keys = jax.random.split(key, 2 + n)
         key_next, tkey, ckeys = keys[0], keys[1], keys[2:]
         lr = jnp.float32(lr0) * jnp.float32(decay) ** t.astype(jnp.float32)
         lr = lr.astype(dtype)
+        leaves = jax.tree.leaves(X)
+        nb = client_block(n, sum(a.size // n * a.dtype.itemsize
+                                 for a in leaves))
 
-        def blocked(a):
-            return a.reshape((n // nb, nb) + a.shape[1:])
+        def local(b, carry):
+            X, v_sq, losses = carry
+            start = b * nb
 
-        def local(args):
-            xb, wb, kb, db = args
-            return jax.vmap(client, in_axes=(0, 0, 0, 0, None))(
-                xb, wb, kb, db, lr)
+            def rows(a):
+                return lax.dynamic_slice_in_dim(a, start, nb)
 
-        Xb, Vb, lb = lax.map(local, (jax.tree.map(blocked, X), blocked(w),
-                                     blocked(ckeys),
-                                     jax.tree.map(blocked, data)))
+            xb, vb, lb = jax.vmap(client, in_axes=(0, 0, 0, 0, None))(
+                jax.tree.map(rows, X), rows(w), rows(ckeys),
+                jax.tree.map(rows, data), lr)
+            X = jax.tree.map(
+                lambda a, u: lax.dynamic_update_slice_in_dim(a, u, start, 0),
+                X, xb)
+            v_sq = v_sq + jnp.stack([jnp.sum(jnp.square(a.astype(jnp.float32)))
+                                     for a in jax.tree.leaves(vb)])
+            losses = lax.dynamic_update_slice_in_dim(
+                losses, lb.astype(jnp.float32), start, 0)
+            return X, v_sq, losses
 
-        def unblock(a):
-            return a.reshape((n,) + a.shape[2:])
-
-        X, V = jax.tree.map(unblock, Xb), jax.tree.map(unblock, Vb)
+        X, v_sq, losses = lax.fori_loop(
+            0, n // nb, local,
+            (X, jnp.zeros((len(leaves),), jnp.float32),
+             jnp.zeros((n,), jnp.float32)))
         idx, wgt = kin_graph(tkey, n, k_out)
         X = jax.tree.map(functools.partial(mix, idx, wgt), X)
         w = mix(idx, wgt, w)
-        return X, V, w, key_next, unblock(lb).astype(jnp.float32)
+        return X, v_sq, w, key_next, losses
 
     return round_fn
 
@@ -153,6 +171,15 @@ def make_eval(apply, precision, chunk=1000):
     return eval_fn
 
 
+@jax.jit
+def _change_norms(X, params0):
+    """``leaf_norms`` of the bank's change from the initial weights, the
+    weights broadcast over the clients inside the reduction."""
+    return leaf_norms(jax.tree.map(
+        lambda a, p: (a.astype(jnp.float32)
+                      - p.astype(a.dtype).astype(jnp.float32)), X, params0))
+
+
 def run_check(model, config, traffic, rounds, params0, round_key, data,
               test, *, control=False, fault=None):
     """The reference's first rounds from the seed's initial state.
@@ -170,21 +197,20 @@ def run_check(model, config, traffic, rounds, params0, round_key, data,
     precision = None if control else lax.Precision.HIGHEST
     round_fn = make_round(model.apply, alg, n, traffic["topology"]["k_out"],
                           dtype=dtype, precision=precision, fault=fault)
-    X0 = jax.tree.map(
+    X = jax.tree.map(
         lambda p: jnp.broadcast_to(p.astype(dtype), (n,) + p.shape), params0)
     cast = (lambda a: a.astype(dtype) if a.dtype == jnp.float32 else a)
     data = jax.tree.map(cast, data)
-    X, w, key = X0, jnp.ones((n,), dtype), round_key
+    w, key = jnp.ones((n,), dtype), round_key
     losses, out = [], {}
     for t in range(last):
-        X, V, w, key, client_losses = round_fn(X, w, key, jnp.int32(t), data)
+        X, v_sq, w, key, client_losses = round_fn(X, w, key, jnp.int32(t),
+                                                  data)
         losses.append(jnp.mean(client_losses))
         if t + 1 == r_grad:
-            out.update(v=leaf_norms(V), client_losses=client_losses)
+            out.update(v=jnp.sqrt(v_sq), client_losses=client_losses)
         if t + 1 == r_change:
-            out["dx"] = leaf_norms(jax.tree.map(
-                lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32),
-                X, X0))
+            out["dx"] = _change_norms(X, params0)
     out.update(losses=jnp.stack(losses), w=w.astype(jnp.float32),
                test_loss=make_eval(model.apply, precision)(
                    X, jax.tree.map(cast, test)))

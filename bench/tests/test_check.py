@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import json
 import os
+import subprocess
+import sys
 
 import pytest
 
@@ -78,3 +80,32 @@ def test_control_is_caught(tmp_path):
     assert not ok
     _, ok = compare.judge(program, _limits())
     assert ok
+
+
+def test_four_chip_run_is_correct(tmp_path):
+    """A whole run of the tiny cell with ``chips: 4``, on four virtual CPU
+    devices: the program's bank sharded over them, the reference on the
+    first."""
+    root = make_root(str(tmp_path), limits=_limits())
+    path = os.path.join(root, "BENCHMARK.json")
+    with open(path) as f:
+        spec = json.load(f)
+    for w in spec["workloads"]:
+        if w["name"] == "mnist_2nn.tiny_k2":
+            w["chips"] = 4
+    with open(path, "w") as f:
+        json.dump(spec, f)
+    flags = os.environ.get("XLA_FLAGS", "")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=root,
+               XLA_FLAGS=f"{flags} --xla_force_host_platform_device_count=4")
+    code = ("import sys; from bench import run; "
+            "sys.exit(run.main(sys.argv[1:], require_tpu=False))")
+    p = subprocess.run(
+        [sys.executable, "-c", code, "--workload", "mnist_2nn.tiny_k2",
+         "--seed", "3000000027", "--seconds", "0.5", "--trace", "0"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=300)
+    assert p.returncode == 0, p.stderr[-4000:]
+    result = json.loads(p.stdout.strip().splitlines()[-1])
+    assert result["device"]["count"] == 4
+    assert result["correct"] is True, result["checks"]
+    assert result["failed"] == 0 and result["attempted"] > 0
